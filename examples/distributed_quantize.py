@@ -24,11 +24,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import quantize_blockwise, reconstruction_mse
 from repro.core.quantize import QTensor, dequantize
 
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-
 def main():
     mesh = jax.make_mesh((4, 2), ("data", "model"),
                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
@@ -43,7 +38,7 @@ def main():
 
     # check_vma off: the DP backtrack scan starts from constant carries,
     # which the varying-axes checker can't classify (solver is shard-pure)
-    solve = jax.jit(shard_map(
+    solve = jax.jit(jax.shard_map(
         local_solve, mesh=mesh, in_specs=P("data", "model"),
         out_specs=(P("data", "model"), P(("data", "model"), None)),
         check_vma=False))

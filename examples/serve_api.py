@@ -338,7 +338,10 @@ def main():
                     help="seed for the --chaos fault plan")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
     from repro.serve import APIServer
+
+    use_compile_cache()
 
     if args.chaos:
         if not args.self_check:

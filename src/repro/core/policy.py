@@ -173,9 +173,11 @@ def _leaf_spec(leaf, kind, axis):
     """
     from jax.sharding import PartitionSpec as P
     if isinstance(leaf, PackedQTensor):
+        # scales (..., N/64, 8, K) n-blocked, (..., K/64, 8, N) k-blocked
         pd, sd = leaf.packed.ndim, leaf.scales.ndim
-        dims = {None: None, "n": (pd - 1, sd - 2), "k": (pd - 2, sd - 3),
-                "e": (pd - 3, sd - 4)}[kind]
+        dims = {None: None,
+                "n": (pd - 1, sd - 1 if leaf.kblocked else sd - 3),
+                "k": (pd - 2, sd - 1), "e": (pd - 3, sd - 4)}[kind]
         if dims is None:
             return dataclasses.replace(leaf, packed=P(), scales=P())
         return dataclasses.replace(leaf, packed=_axis_spec(pd, dims[0], axis),
@@ -320,7 +322,9 @@ def tp_partition_params(params, tp_size, cfg=None, axis="model",
         if isinstance(leaf, PackedQTensor):
             if not leaf.kblocked:
                 return leaf, _leaf_spec(leaf, None, None)
-            v_to = _roundup(leaf.n_pad, 64 * tp)
+            # whole 256-column tiles per rank: the kernel's k-blocked
+            # column tile (kernels/msb_matmul pick_blocks)
+            v_to = _roundup(leaf.n_pad, 256 * tp)
             leaf = tp_pad_packed_n(leaf, v_to)
             kind = "n"
         else:

@@ -10,8 +10,9 @@ Stored form:
           single row (per-tensor)
 
 ``QTensor`` is a pytree, so quantized params flow through jit / device_put /
-sharding like any array. A packed int4 form (two codes per byte) feeds the
-Pallas fused dequant-matmul kernel (kernels/msb_matmul).
+sharding like any array. A packed int4 form (two codes per byte, paired
+across the halves of each 64-element block) feeds the Pallas fused
+dequant-matmul kernel (kernels/msb_matmul).
 """
 from __future__ import annotations
 
@@ -170,29 +171,46 @@ def dequantize(q: QTensor):
 # int4 packing (deployment path for the Pallas kernel)
 # ---------------------------------------------------------------------------
 
+PACK_BLOCK = 64      # MSB block size — matches kernels/msb_matmul BLOCK
+PACK_LEVELS = 8      # 2^(4-1) codebook entries per block
+
+
 def pack_codes_int4(codes):
-    """Pack 4-bit MSB codes two-per-byte.
+    """Pack 4-bit MSB codes two-per-byte along the last axis.
 
     nibble = (sign_bit << 3) | level, level in [0, 8). Exact zeros (code 0)
     pack as level 0 / sign + (they dequantize to +alpha_0 — the packed path
     trades the zero special-case for density; see DESIGN.md Sec. 7).
-    Element 2i -> low nibble, 2i+1 -> high nibble.
+
+    Layout (DESIGN.md Sec. 9): within each 64-element block of the last
+    axis, byte ``j`` holds element ``j`` in its low nibble and element
+    ``j + 32`` in its high nibble. The two nibbles of a packed row thus
+    fill two contiguous 32-wide halves of the block, so the kernel unpacks
+    with lane slices and a concatenation — never a lane interleave, which
+    the TPU compiler does not lower in reasonable time.
     """
-    flat = codes.reshape(-1)
-    if flat.shape[0] % 2:
-        raise ValueError("packing requires an even element count")
-    lv = jnp.maximum(jnp.abs(flat).astype(jnp.int32) - 1, 0)
-    sign_bit = (flat < 0).astype(jnp.int32)
-    nib = (sign_bit << 3) | lv
-    lo, hi = nib[0::2], nib[1::2]
-    return ((hi << 4) | lo).astype(jnp.uint8)
+    if codes.ndim == 0 or codes.shape[-1] % PACK_BLOCK:
+        raise ValueError(f"packing needs a last dim divisible by "
+                         f"{PACK_BLOCK}, got shape {codes.shape}")
+    c32 = codes.astype(jnp.int32)
+    lv = jnp.maximum(jnp.abs(c32) - 1, 0)
+    nib = ((c32 < 0).astype(jnp.int32) << 3) | lv
+    halves = nib.reshape(*nib.shape[:-1], -1, 2, PACK_BLOCK // 2)
+    byte = (halves[..., 1, :] << 4) | halves[..., 0, :]
+    return byte.reshape(*nib.shape[:-1], -1).astype(jnp.uint8)
+
+
+def _unpack_nibble_codes(packed):
+    """uint8 (..., half) -> nibbles int32 (..., 2*half); inverse layout of
+    ``pack_codes_int4``."""
+    p32 = packed.astype(jnp.int32)
+    blocks = p32.reshape(*p32.shape[:-1], -1, PACK_BLOCK // 2)
+    nib = jnp.stack([blocks & 0xF, (blocks >> 4) & 0xF], axis=-2)
+    return nib.reshape(*p32.shape[:-1], 2 * p32.shape[-1])
 
 
 def unpack_codes_int4(packed, shape):
-    packed = packed.astype(jnp.int32)
-    lo = packed & 0xF
-    hi = (packed >> 4) & 0xF
-    nib = jnp.stack([lo, hi], axis=-1).reshape(-1)
+    nib = _unpack_nibble_codes(packed)
     lv = (nib & 0x7).astype(jnp.int8) + 1
     sign = jnp.where((nib >> 3) & 1 > 0, jnp.int8(-1), jnp.int8(1))
     return (sign * lv).reshape(shape)
@@ -201,10 +219,6 @@ def unpack_codes_int4(packed, shape):
 # ---------------------------------------------------------------------------
 # Packed execution format (deployment path; DESIGN.md Sec. 9)
 # ---------------------------------------------------------------------------
-
-PACK_BLOCK = 64      # MSB block size — matches kernels/msb_matmul BLOCK
-PACK_LEVELS = 8      # 2^(4-1) codebook entries per block
-
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)
@@ -216,11 +230,15 @@ class PackedQTensor:
     (``n`` keeps the logical width; padded columns carry zero scales and
     dequantize to exact 0, so the matmul wrapper just slices them off).
 
-    Two scale layouts, both one codebook row per 64-element block:
+    Two scale layouts, both one 8-entry codebook per 64-element block, with
+    the codebook axis second to last and a long axis last. TPU tiles the two
+    minor dims of an array as (8, 128), so this layout is dense in HBM and
+    in VMEM; a trailing codebook axis of 8 would be padded 16x wherever the
+    kernel needs its operand in row-major layout.
       * n-blocked (default): blocks run along N — ``scales`` is
-        ``(..., K, N_pad // 64, 8)``. This is every dense (in, out) weight.
+        ``(..., N_pad // 64, 8, K)``. This is every dense (in, out) weight.
       * k-blocked (``kblocked=True``): blocks run along K — ``scales`` is
-        ``(..., K // 64, N_pad, 8)``. Produced by ``transpose=True`` packing
+        ``(..., K // 64, 8, N_pad)``. Produced by ``transpose=True`` packing
         of a ``(V, D)`` table so the unembedding projection
         ``x (B, D) @ table^T (D, V)`` hits the fused kernel without
         re-quantizing: the original block-along-D grouping *is* the
@@ -297,11 +315,8 @@ def pack_qtensor(q: QTensor, *, transpose=False) -> PackedQTensor:
         codes = codes.T                                     # (D, V)
         n = v
         codes = _pad_last(codes, PACK_BLOCK)
-        # (V, D//64, 8) -> (D//64, V, 8), pad V with zero-scale columns
-        scales = jnp.moveaxis(scales, 0, 1)
-        pad = codes.shape[-1] - v
-        if pad:
-            scales = jnp.pad(scales, ((0, 0), (0, pad), (0, 0)))
+        # (V, D//64, 8) -> (D//64, 8, V), pad V with zero-scale columns
+        scales = _pad_last(jnp.transpose(scales, (1, 2, 0)), PACK_BLOCK)
     else:
         n = codes.shape[-1]
         codes = _pad_last(codes, PACK_BLOCK)
@@ -309,19 +324,16 @@ def pack_qtensor(q: QTensor, *, transpose=False) -> PackedQTensor:
         if pad_blocks:
             widths = [(0, 0)] * (scales.ndim - 2) + [(0, pad_blocks), (0, 0)]
             scales = jnp.pad(scales, widths)
-    n_pad = codes.shape[-1]
-    packed = pack_codes_int4(codes).reshape(*codes.shape[:-1], n_pad // 2)
+        # (..., K, N_pad//64, 8) -> (..., N_pad//64, 8, K)
+        scales = jnp.moveaxis(scales, -3, -1)
+    packed = pack_codes_int4(codes)
     return PackedQTensor(packed, scales, q.bits, q.block, q.dtype, n,
                          kblocked=transpose)
 
 
 def _unpack_nibbles(packed):
     """uint8 (..., half) -> (level int32, sign f32), both (..., 2*half)."""
-    p32 = packed.astype(jnp.int32)
-    lo = p32 & 0xF
-    hi = (p32 >> 4) & 0xF
-    nib = jnp.stack([lo, hi], axis=-1).reshape(*packed.shape[:-1],
-                                               packed.shape[-1] * 2)
+    nib = _unpack_nibble_codes(packed)
     level = nib & 0x7
     sign = (1 - 2 * ((nib >> 3) & 1)).astype(jnp.float32)
     return level, sign
@@ -339,18 +351,19 @@ def packed_dequantize(pq: PackedQTensor):
     n_pad = level.shape[-1]
     sc = pq.scales.astype(jnp.float32)
     if pq.kblocked:
-        # sc (..., K//64, N_pad, 8): block index = k // 64. Gather per
+        # sc (..., K//64, 8, N_pad): block index = k // 64. Gather per
         # (k-block, n) with levels brought to (..., K//64, N_pad, 64).
         k = level.shape[-2]
         lv = level.reshape(*level.shape[:-2], k // PACK_BLOCK, PACK_BLOCK,
                            n_pad)
         lvb = jnp.moveaxis(lv, -2, -1)             # (..., K//64, N_pad, 64)
-        magb = jnp.take_along_axis(sc, lvb, axis=-1)
+        magb = jnp.take_along_axis(jnp.swapaxes(sc, -1, -2), lvb, axis=-1)
         mag = jnp.moveaxis(magb, -1, -2).reshape(*level.shape)
     else:
-        # sc (..., K, N_pad//64, 8): block index = n // 64
+        # sc (..., N_pad//64, 8, K): block index = n // 64
         lv = level.reshape(*level.shape[:-1], n_pad // PACK_BLOCK, PACK_BLOCK)
-        mag = jnp.take_along_axis(sc, lv, axis=-1).reshape(*level.shape)
+        mag = jnp.take_along_axis(jnp.moveaxis(sc, -1, -3), lv,
+                                  axis=-1).reshape(*level.shape)
     w = sign * mag
     return w[..., : pq.n].astype(pq.dtype)
 
@@ -365,7 +378,8 @@ def packed_gather(pq: PackedQTensor, idx):
     if pq.kblocked:
         raise ValueError("packed_gather needs natural (n-blocked) layout")
     rows = jnp.take(pq.packed, idx, axis=0)        # (..., N_pad//2)
-    srow = jnp.take(pq.scales, idx, axis=0)        # (..., N_pad//64, 8)
+    # scales (N_pad//64, 8, V) -> rows (..., N_pad//64, 8)
+    srow = jnp.moveaxis(jnp.take(pq.scales, idx, axis=-1), (0, 1), (-2, -1))
     level, sign = _unpack_nibbles(rows)
     n_pad = level.shape[-1]
     lv = level.reshape(*level.shape[:-1], n_pad // PACK_BLOCK, PACK_BLOCK)
@@ -578,9 +592,9 @@ def tp_pad_packed_n(pq: PackedQTensor, n_to: int) -> PackedQTensor:
         raise ValueError(f"cannot shrink storage {pq.n_pad} -> {n_to}")
     packed = _pad_axis(pq.packed, -1, n_to // 2)
     if pq.kblocked:
-        scales = _pad_axis(pq.scales, -2, n_to)
+        scales = _pad_axis(pq.scales, -1, n_to)
     else:
-        scales = _pad_axis(pq.scales, -2, n_to // PACK_BLOCK)
+        scales = _pad_axis(pq.scales, -3, n_to // PACK_BLOCK)
     return dataclasses.replace(pq, packed=packed, scales=scales, n=n_to)
 
 
@@ -592,7 +606,7 @@ def tp_pad_packed_k(pq: PackedQTensor, k_to: int) -> PackedQTensor:
         raise ValueError(f"cannot shrink K {pq.packed.shape[-2]} -> {k_to}")
     return dataclasses.replace(pq,
                                packed=_pad_axis(pq.packed, -2, k_to),
-                               scales=_pad_axis(pq.scales, -3, k_to))
+                               scales=_pad_axis(pq.scales, -1, k_to))
 
 
 def tp_pad_q_n(q: QTensor, n_to: int) -> QTensor:

@@ -51,8 +51,8 @@ def _kernel(*refs, bq, bkv, skv, causal, window, softcap, scale, segmented):
 
     def body(ik, carry):
         acc, m_i, l_i = carry
-        kt = pl.load(k_ref, (pl.dslice(ik * bkv, bkv), slice(None)))
-        vt = pl.load(v_ref, (pl.dslice(ik * bkv, bkv), slice(None)))
+        kt = k_ref[pl.ds(ik * bkv, bkv), :]
+        vt = v_ref[pl.ds(ik * bkv, bkv), :]
         s = jnp.dot(q, kt.astype(jnp.float32).T,
                     preferred_element_type=jnp.float32)  # (bq, bkv)
         if softcap > 0:
@@ -64,7 +64,7 @@ def _kernel(*refs, bq, bkv, skv, causal, window, softcap, scale, segmented):
         if window > 0:
             mask &= kpos[None, :] > qpos[:, None] - window
         if segmented:
-            kseg = pl.load(ks_ref, (pl.dslice(ik * bkv, bkv),))
+            kseg = ks_ref[pl.ds(ik * bkv, bkv)]
             # same segment, and neither side a pad (-1): pads never attend
             mask &= (kseg[None, :] == qseg[:, None]) & (qseg[:, None] >= 0)
         s = jnp.where(mask, s, _NEG_INF)

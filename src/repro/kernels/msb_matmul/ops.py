@@ -17,9 +17,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ...core.quantize import (PackedQTensor, QTensor, pack_codes_int4,
-                              pack_qtensor)
-from .msb_matmul import BLOCK, LEVELS, msb_matmul
+from ...core.quantize import PackedQTensor, QTensor, pack_qtensor
+from .msb_matmul import BLOCK, msb_matmul
 from .ref import msb_matmul_ref
 
 
@@ -72,12 +71,10 @@ def packed_matmul(x, pq: PackedQTensor, bias=None, *, use_kernel=None,
 # -- QTensor conveniences (tests / benchmarks) -------------------------------
 
 def to_kernel_layout(q: QTensor):
-    """QTensor (codes (K,N), scales (K*N/64, 8)) -> (packed, scales3d)."""
+    """2-D QTensor (codes (K, N)) -> kernel (packed, n-blocked scales)."""
     assert q.bits == 4 and q.block == BLOCK, "kernel supports 4-bit block-64"
-    k, n = q.codes.shape
-    packed = pack_codes_int4(q.codes).reshape(k, n // 2)
-    scales = q.scales.reshape(k, n // BLOCK, LEVELS)
-    return packed, scales
+    pq = pack_qtensor(q)
+    return pq.packed, pq.scales
 
 
 _PACK_CACHE = {}

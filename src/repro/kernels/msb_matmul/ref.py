@@ -24,7 +24,7 @@ def unpack_ref(packed, n):
 def dequant_ref(packed, scales, kblocked=False):
     """Dequantize to (K, N) f32.
 
-    scales: (K, N//64, 8) n-blocked, or (K//64, N, 8) k-blocked."""
+    scales: (N//64, 8, K) n-blocked, or (K//64, 8, N) k-blocked."""
     n = packed.shape[-1] * 2
     pq = PackedQTensor(packed, scales, 4, BLOCK, jnp.float32, n,
                        kblocked=kblocked)

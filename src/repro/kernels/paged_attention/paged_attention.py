@@ -37,9 +37,8 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, *, ps, softcap,
     def body(j, carry):
         acc, m_i, l_i = carry
         page = bt_ref[j]
-        kt = pl.load(k_ref, (pl.dslice(page, 1), slice(None),
-                             slice(None)))[0]              # (ps, d)
-        vt = pl.load(v_ref, (pl.dslice(page, 1), slice(None), slice(None)))[0]
+        kt = k_ref[page]                                   # (ps, d)
+        vt = v_ref[page]
         s = jnp.dot(q, kt.astype(jnp.float32).T,
                     preferred_element_type=jnp.float32)    # (rep, ps)
         if softcap > 0:
@@ -98,20 +97,10 @@ def _quant_kernel(bt_ref, len_ref, row_ref, q_ref, kc_ref, ks_ref, vc_ref,
     def body(j, carry):
         acc, m_i, l_i = carry
         page = bt_ref[j]
-        kt_q = _dequant_tile(
-            pl.load(kc_ref, (pl.dslice(page, 1), slice(None),
-                             slice(None)))[0],
-            pl.load(ks_ref, (pl.dslice(page, 1), slice(None),
-                             slice(None)))[0], kv_bits, ps, hd)
-        vt_q = _dequant_tile(
-            pl.load(vc_ref, (pl.dslice(page, 1), slice(None),
-                             slice(None)))[0],
-            pl.load(vs_ref, (pl.dslice(page, 1), slice(None),
-                             slice(None)))[0], kv_bits, ps, hd)
-        kh = pl.load(kh_ref, (pl.dslice(hot_row, 1), slice(None),
-                              slice(None)))[0].astype(jnp.float32)
-        vh = pl.load(vh_ref, (pl.dslice(hot_row, 1), slice(None),
-                              slice(None)))[0].astype(jnp.float32)
+        kt_q = _dequant_tile(kc_ref[page], ks_ref[page], kv_bits, ps, hd)
+        vt_q = _dequant_tile(vc_ref[page], vs_ref[page], kv_bits, ps, hd)
+        kh = kh_ref[hot_row].astype(jnp.float32)
+        vh = vh_ref[hot_row].astype(jnp.float32)
         # page j == n_full is the partial frontier page: full precision
         # from the hot row; committed pages stream dequantized
         is_tail = j >= n_full

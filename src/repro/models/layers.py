@@ -93,9 +93,6 @@ def _cp_attention(q, k, v, parallel, *, causal, window, softcap, scale,
     the full K/V with globally-correct causal offsets."""
     import jax as _jax
     P = _jax.sharding.PartitionSpec
-    shard_map = getattr(_jax, "shard_map", None)
-    if shard_map is None:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
     tp = parallel.tp_axis
     dp = parallel.dp_axes
 
@@ -108,8 +105,9 @@ def _cp_attention(q, k, v, parallel, *, causal, window, softcap, scale,
                                chunk_kv=chunk, q_offset=off)
 
     spec = P(dp, tp, None, None)
-    return shard_map(inner, mesh=parallel.mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
+    return _jax.shard_map(inner, mesh=parallel.mesh,
+                          in_specs=(spec, spec, spec), out_specs=spec,
+                          check_vma=False)(q, k, v)
 
 
 def _attn_out_proj(out, wo, tp, full_h):

@@ -25,11 +25,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.6 public API
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def _route(x2d, router_w, e_total, n_real, k, capacity):
     """Top-k routing + per-expert positions for local tokens.
@@ -252,7 +247,7 @@ def moe_layer(p, x, cfg, parallel=None):
         return y.reshape(xl.shape), aux
 
     wspec = P(tp_axis, fsdp, None)
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(dp_axes, tp_axis, None), P(None, None),
                   wspec, wspec, wspec),

@@ -1,2 +1,2 @@
 from .sharding import (ParallelContext, TPShard, constraint, from_mesh,
-                       resolve_spec, shard_map_compat, tree_shardings)
+                       resolve_spec, tree_shardings)

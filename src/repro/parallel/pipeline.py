@@ -18,9 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def pipeline_forward(layer_fn, params, x_micro, mesh, stage_axis="stage"):

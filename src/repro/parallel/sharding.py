@@ -48,26 +48,6 @@ class TPShard:
     size: int = 1
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions, replication checking off.
-
-    The manual-TP step produces replicated outputs by construction (psum /
-    all_gather); the rep/vma checker of some jax versions cannot prove
-    that through ``axis_index``-based head slicing, so it is disabled.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:  # pragma: no cover - older keyword
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
 @dataclasses.dataclass(frozen=True)
 class ParallelContext:
     mesh: Mesh

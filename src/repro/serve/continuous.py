@@ -70,15 +70,6 @@ import numpy as np
 from .paged_cache import PagedKVCache
 from .scheduler import DECODE, FINISHED, Request, Scheduler, Sequence
 
-# Module-level jit, model static (frozen dataclass, hashable): every engine
-# for the same model shares one compile cache, and the pools are donated so
-# the per-step cache update is in place (donation is a no-op warning on
-# backends without buffer aliasing, e.g. CPU, so it's gated). kv_bits is
-# static (16 native pools, 8|4 the dual quantized pools of DESIGN.md
-# Sec. 15); slots maps batch rows to engine slots so quantized writes hit
-# the right hot row (-1 pads land on the scratch row).
-_DONATE = (2,) if jax.default_backend() in ("tpu", "gpu") else ()
-
 # Trace-count probe (DESIGN.md Sec. 16): a Python-side counter bumped at
 # the top of every traced dispatch body. jit runs the Python body once per
 # trace, never per call, so after AOT warmup a steady-state serving run
@@ -98,7 +89,14 @@ def jit_trace_count() -> int:
     return _N_TRACES
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=_DONATE)
+# Module-level jits, model static (frozen dataclass, hashable): every engine
+# for the same model shares one compile cache, and the pools are donated so
+# the per-step cache update is in place (every backend, CPU included,
+# aliases donated buffers). kv_bits is static (16 native pools, 8|4 the dual
+# quantized pools of DESIGN.md Sec. 15); slots maps batch rows to engine
+# slots so quantized writes hit the right hot row (-1 pads land on the
+# scratch row).
+@functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2,))
 def _paged_step(model, kv_bits, pools, params, tokens, q_pos, kv_lens,
                 block_tables, slots):
     _note_trace()
@@ -106,7 +104,7 @@ def _paged_step(model, kv_bits, pools, params, tokens, q_pos, kv_lens,
                             block_tables, kv_bits=kv_bits, slots=slots)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=_DONATE)
+@functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2,))
 def _paged_prefill_packed(model, kv_bits, pools, params, tokens, seg_ids,
                           q_pos, kv_lens, block_tables, slots, last_idx,
                           seg_off):
@@ -118,10 +116,7 @@ def _paged_prefill_packed(model, kv_bits, pools, params, tokens, seg_ids,
 
 # decode-horizon dispatch: pools is positional arg 3 here (model, the
 # static horizon and kv_bits precede it), hence the shifted donation index
-_DONATE_H = (3,) if jax.default_backend() in ("tpu", "gpu") else ()
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1, 2), donate_argnums=_DONATE_H)
+@functools.partial(jax.jit, static_argnums=(0, 1, 2), donate_argnums=(3,))
 def _paged_horizon_step(model, horizon, kv_bits, pools, params, tokens,
                         start_pos, n_left, eos_ids, block_tables, slots):
     _note_trace()
@@ -273,7 +268,7 @@ class ContinuousEngine:
         """
         from jax.sharding import NamedSharding, PartitionSpec as P
         from ..core.policy import tp_localize, tp_partition_params
-        from ..parallel.sharding import TPShard, from_mesh, shard_map_compat
+        from ..parallel.sharding import TPShard, from_mesh
         ctx = from_mesh(self.mesh)
         tp = TPShard(axis=ctx.tp_axis, size=ctx.tp_size)
         self.tp = tp
@@ -313,12 +308,13 @@ class ContinuousEngine:
                                     q_pos, kv_lens, bt, parallel=tp,
                                     kv_bits=kv_bits, slots=slots)
 
-        fn = shard_map_compat(
-            local_step, self.mesh,
+        # outputs are replicated by construction (psum / all_gather), which
+        # the vma checker cannot prove through axis_index head slicing
+        fn = jax.shard_map(
+            local_step, mesh=self.mesh, check_vma=False,
             in_specs=(pool_spec, pspecs, rep, rep, rep, rep, rep),
             out_specs=(rep, pool_spec))
-        donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
-        self._step_fn = jax.jit(fn, donate_argnums=donate)
+        self._step_fn = jax.jit(fn, donate_argnums=(0,))
 
         # packed ragged prefill under the same mesh: control arrays (seg
         # ids, positions, tables, offsets) are replicated; only the pools
@@ -331,12 +327,12 @@ class ContinuousEngine:
                 tp_localize(params), pools, tokens, seg_ids, q_pos, kv_lens,
                 bt, slots, last_idx, seg_off, parallel=tp, kv_bits=kv_bits)
 
-        pfn = shard_map_compat(
-            local_prefill, self.mesh,
+        pfn = jax.shard_map(
+            local_prefill, mesh=self.mesh, check_vma=False,
             in_specs=(pool_spec, pspecs, rep, rep, rep, rep, rep, rep, rep,
                       rep),
             out_specs=(rep, pool_spec))
-        self._prefill_fn = jax.jit(pfn, donate_argnums=donate)
+        self._prefill_fn = jax.jit(pfn, donate_argnums=(0,))
 
         # the decode-horizon scan lives *inside* the shard_map body, so H
         # fused iterations (collectives included) are still one dispatch
@@ -349,11 +345,11 @@ class ContinuousEngine:
                 tp_localize(params), pools, tokens, start_pos, bt, n_left,
                 eos, horizon, parallel=tp, kv_bits=kv_bits, slots=slots)
 
-        hfn = shard_map_compat(
-            local_horizon, self.mesh,
+        hfn = jax.shard_map(
+            local_horizon, mesh=self.mesh, check_vma=False,
             in_specs=(pool_spec, pspecs, rep, rep, rep, rep, rep, rep),
             out_specs=(rep, rep, pool_spec))
-        self._horizon_fn = jax.jit(hfn, donate_argnums=donate)
+        self._horizon_fn = jax.jit(hfn, donate_argnums=(0,))
 
     # -- API ----------------------------------------------------------------
     def submit(self, prompt, max_new_tokens, eos_id=None,

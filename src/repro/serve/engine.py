@@ -19,11 +19,14 @@ recurrent state).
 from __future__ import annotations
 
 import dataclasses
+import logging
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+log = logging.getLogger(__name__)
 
 
 def resolve_execution(execution, params):
@@ -36,7 +39,10 @@ def resolve_execution(execution, params):
     jnp packed fallback is correct off-TPU but pays unpack cost per call.
     """
     if execution is None:
-        execution = "packed" if jax.default_backend() == "tpu" else "simulated"
+        backend = jax.default_backend()
+        execution = "packed" if backend == "tpu" else "simulated"
+        log.warning("execution=None on backend %r: serving %r", backend,
+                    execution)
     if execution == "packed":
         from ..core.policy import pack_params
         params, _ = pack_params(params)
@@ -103,7 +109,7 @@ class ServeEngine:
         """
         from jax.sharding import NamedSharding, PartitionSpec as P
         from ..core.policy import tp_localize, tp_partition_params
-        from ..parallel.sharding import TPShard, from_mesh, shard_map_compat
+        from ..parallel.sharding import TPShard, from_mesh
         ctx = from_mesh(self.mesh)
         tp = TPShard(axis=ctx.tp_axis, size=ctx.tp_size)
         self.tp = tp
@@ -132,12 +138,14 @@ class ServeEngine:
             return model.decode_step(tp_localize(params), cache, tokens,
                                      pos, tp)
 
-        self._prefill = jax.jit(shard_map_compat(
-            local_prefill, self.mesh, in_specs=(pspecs, rep),
-            out_specs=(rep, cspecs)))
-        self._decode = jax.jit(shard_map_compat(
-            local_decode, self.mesh, in_specs=(pspecs, cspecs, rep, rep),
-            out_specs=(rep, cspecs)))
+        # outputs are replicated by construction (psum / all_gather), which
+        # the vma checker cannot prove through axis_index head slicing
+        self._prefill = jax.jit(jax.shard_map(
+            local_prefill, mesh=self.mesh, check_vma=False,
+            in_specs=(pspecs, rep), out_specs=(rep, cspecs)))
+        self._decode = jax.jit(jax.shard_map(
+            local_decode, mesh=self.mesh, check_vma=False,
+            in_specs=(pspecs, cspecs, rep, rep), out_specs=(rep, cspecs)))
 
     def _grow_cache(self, cache, prompt_len):
         """Re-home prefill caches (length P) into max_seq ring buffers.

@@ -146,9 +146,8 @@ class PagedKVCache:
         self._versions = np.zeros((max_seqs,), np.int64)
         self._rows_cache: Dict[Tuple[int, ...],
                                Tuple[Tuple[int, ...], jnp.ndarray]] = {}
-        donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
-        self._copy_page = jax.jit(self._copy_page_impl, donate_argnums=donate)
-        self._copy_hot = jax.jit(self._copy_hot_impl, donate_argnums=donate)
+        self._copy_page = jax.jit(self._copy_page_impl, donate_argnums=(0,))
+        self._copy_hot = jax.jit(self._copy_hot_impl, donate_argnums=(0,))
 
     # -- capacity ----------------------------------------------------------
     @property

@@ -17,7 +17,7 @@ the reserved scratch page (and hot row 0 on the quantized pools) and the
 dispatch is semantically a no-op — but it populates the *call-site* jit
 cache, which an offline ``lower().compile()`` would not, and it exercises
 the exact aval set steady-state serving uses. Pools are reassigned from
-the returned tree so donation on TPU/GPU stays correct.
+the returned tree, since the dispatches donate them.
 
 After ``warm_engine`` returns, a serving run that stays inside the
 config's shape envelope performs zero new traces — the property the

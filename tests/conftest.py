@@ -1,61 +1,11 @@
 """Test env: 8 host devices for the distributed tests (NOT the dry-run's
-512 — that flag lives only in launch/dryrun.py per the assignment).
-
-``requires_env`` marker: a handful of tier-1 tests exercise jax APIs that
-not every runtime in the support window ships (``jax.sharding.AxisType``
-explicit-mesh types; dict-shaped ``compiled.cost_analysis()``). They are
-marked ``@pytest.mark.requires_env("<capability>")`` and skip — with the
-missing capability named — on runtimes that lack it, so a clean run
-reports 0 failures everywhere and any *unmarked* failure is a real
-regression CI must reject.
-"""
+512 — that flag lives only in launch/dryrun.py per the assignment)."""
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
-
-_CAPS = None
-
-
-def _env_capabilities():
-    """Probe the jax runtime once per session for the optional capabilities
-    the marked tests need. Probes are behavioural (try it), not version
-    string comparisons — forks and backports stay honest."""
-    global _CAPS
-    if _CAPS is None:
-        import jax
-
-        caps = {"axis_type": hasattr(jax.sharding, "AxisType")}
-        try:
-            compiled = jax.jit(lambda x: x + 1.0).lower(1.0).compile()
-            caps["dict_cost_analysis"] = isinstance(
-                compiled.cost_analysis(), dict)
-        except Exception:
-            caps["dict_cost_analysis"] = False
-        _CAPS = caps
-    return _CAPS
-
-
-def pytest_configure(config):
-    config.addinivalue_line(
-        "markers",
-        "requires_env(capability): skip when the jax runtime lacks the "
-        "named capability ('axis_type' = jax.sharding.AxisType explicit "
-        "mesh axis types; 'dict_cost_analysis' = dict-shaped "
-        "Compiled.cost_analysis())")
-
-
-def pytest_collection_modifyitems(config, items):
-    for item in items:
-        for mark in item.iter_markers("requires_env"):
-            missing = [c for c in mark.args
-                       if not _env_capabilities().get(c, False)]
-            if missing:
-                item.add_marker(pytest.mark.skip(
-                    reason="jax runtime lacks capability "
-                           f"{'/'.join(missing)} (requires_env)"))
 
 
 @pytest.fixture

@@ -63,7 +63,6 @@ def test_incomplete_checkpoint_ignored(state, tmp_path):
     assert ck.steps() == [3]
 
 
-@pytest.mark.requires_env("axis_type")
 def test_elastic_reshard(state, tmp_path):
     """Save under one sharding, restore onto a different mesh layout."""
     devs = jax.devices()

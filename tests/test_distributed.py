@@ -20,7 +20,6 @@ def make_mesh(shape=(4, 2), axes=("data", "model")):
                          axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-@pytest.mark.requires_env("axis_type")
 def test_resolver_rules():
     ctx = from_mesh(make_mesh())
     # divisible dims get sharded
@@ -40,7 +39,6 @@ def test_resolver_rules():
 
 @pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "granite-moe-3b-a800m",
                                   "jamba-v0.1-52b"])
-@pytest.mark.requires_env("axis_type")
 def test_sharded_loss_matches_single_device(arch):
     """The distributed forward is numerically the single-device forward."""
     cfg = smoke_config(arch)
@@ -69,7 +67,6 @@ def test_sharded_loss_matches_single_device(arch):
     assert float(loss_8) == pytest.approx(float(loss_1), rel=tol)
 
 
-@pytest.mark.requires_env("axis_type")
 def test_moe_ep_matches_local(rng):
     """shard_map all-to-all EP == single-device dispatch (same capacity)."""
     cfg = dataclasses.replace(smoke_config("granite-moe-3b-a800m"),
@@ -97,7 +94,6 @@ def test_moe_ep_matches_local(rng):
                                atol=5e-4, rtol=5e-3)
 
 
-@pytest.mark.requires_env("axis_type")
 def test_sharded_train_step_runs(rng):
     cfg = smoke_config("internlm2-1.8b")
     model = Model(cfg)
@@ -142,7 +138,6 @@ def test_compression_error_feedback(rng):
     assert q.dtype == jnp.int8
 
 
-@pytest.mark.requires_env("axis_type")
 def test_psum_compressed_under_shard_map(rng):
     from repro.train.compression import psum_compressed
     mesh = make_mesh((8,), ("data",))
@@ -153,11 +148,8 @@ def test_psum_compressed_under_shard_map(rng):
         avg, new_res = psum_compressed(gl[0], rl[0], "data")
         return avg[None], new_res[None]
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
     with mesh:
-        avg, _ = jax.jit(shard_map(
+        avg, _ = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(P("data", None), P("data", None)),
             out_specs=(P("data", None), P("data", None))))(g, res)
     true_avg = np.asarray(g).mean(axis=0)

@@ -8,7 +8,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.parallel.pipeline import pipeline_forward
 
 
-@pytest.mark.requires_env("axis_type")
 def test_pipeline_matches_sequential(rng):
     n_stages, n_micro, mb, d = 4, 6, 2, 8
     mesh = jax.make_mesh((n_stages,), ("stage",),

@@ -27,7 +27,7 @@ def test_pack_unpack_round_trip_nonzero(rng):
 
 
 def test_pack_unpack_negative_codes(rng):
-    codes = -np.arange(1, 9, dtype=np.int8).repeat(2)   # all 8 negative codes
+    codes = -np.arange(1, 9, dtype=np.int8).repeat(8)   # all 8 negative codes
     out = np.asarray(unpack_codes_int4(
         pack_codes_int4(jnp.asarray(codes)), codes.shape))
     np.testing.assert_array_equal(out, codes)
@@ -47,10 +47,24 @@ def test_pack_zero_densifies_to_code_one(rng):
 def test_pack_rejects_odd_length():
     with pytest.raises(ValueError):
         pack_codes_int4(jnp.ones((3,), jnp.int8))
+    with pytest.raises(ValueError):              # not whole 64-blocks
+        pack_codes_int4(jnp.ones((96,), jnp.int8))
+
+
+def test_pack_layout_pairs_block_halves():
+    """Byte j of each 64-block holds elements j (low nibble) and j + 32
+    (high nibble): the layout the kernel unpacks without a lane interleave."""
+    codes = np.ones(128, np.int8)
+    codes[[0, 32 + 5, 64 + 31, 127]] = [2, -3, 4, -8]
+    packed = np.asarray(pack_codes_int4(jnp.asarray(codes)))
+    assert packed.shape == (64,)
+    assert packed[0] == 0x01                    # lo: level 1; hi: code 1
+    assert packed[5] == (0b1010 << 4)           # hi: sign | level 2
+    assert packed[32 + 31] == (0b1111 << 4) | 3
 
 
 def test_pack_unpack_2d_shape(rng):
-    codes = _random_codes(rng, 128).reshape(8, 16)
+    codes = _random_codes(rng, 512).reshape(8, 64)
     codes[codes == 0] = 2
     out = np.asarray(unpack_codes_int4(
         pack_codes_int4(jnp.asarray(codes)), codes.shape))

@@ -9,7 +9,6 @@ from repro.launch import hlo_cost
 from repro.launch.roofline import Roofline, parse_collectives
 
 
-@pytest.mark.requires_env("dict_cost_analysis")
 def test_scan_flops_multiplied():
     """A scan of L matmuls must be charged L*flops, not 1x (the XLA
     cost_analysis undercount this module exists to fix)."""
@@ -31,7 +30,6 @@ def test_scan_flops_multiplied():
     assert xla < 0.5 * analytic  # the undercount we correct
 
 
-@pytest.mark.requires_env("axis_type")
 def test_collectives_counted_with_wire_factors():
     devs = jax.devices()
     mesh = jax.make_mesh((8,), ("d",),
