@@ -216,11 +216,18 @@ def run_direct(model, params, prompts):
     return [out[r].tolist() for r in rids]
 
 
+def retraced_since(before, after):
+    """Traces per dispatch function between two ``jit_trace_counts()``
+    readings, the functions that traced none left out."""
+    return {k: n - before.get(k, 0) for k, n in after.items()
+            if n > before.get(k, 0)}
+
+
 # -- phases --------------------------------------------------------------------
 
 def one_chip(seed):
     import jax
-    from repro.serve import APIServer, ContinuousEngine, jit_trace_count
+    from repro.serve import APIServer, ContinuousEngine, jit_trace_counts
 
     dev = tpu_devices(1)[0]
     model, qparams, secs = build(seed)
@@ -263,7 +270,7 @@ def one_chip(seed):
         secs["warmup"] = eng.warmup_seconds
         peaks["warmup"] = peak_bytes(dev)
         log(f"warmup: {eng.warmup_entries} dispatch shapes compiled")
-        traces0 = jit_trace_count()
+        traces0 = jit_trace_counts()
         got = [None] * len(REQUESTS)
 
         def send(i):
@@ -273,7 +280,7 @@ def one_chip(seed):
         send(0)
         with ThreadPoolExecutor(len(REQUESTS) - 1) as pool:
             list(pool.map(send, range(1, len(REQUESTS))))
-        n_traced = jit_trace_count() - traces0
+        traced = retraced_since(traces0, jit_trace_counts())
     finally:
         srv.close()
     if srv.engine_loop.died is not None:
@@ -290,8 +297,8 @@ def one_chip(seed):
         f"({sum(r[2] for r in REQUESTS)} streamed), {n_served} tokens, "
         f"all full length; prefix hits {st['prefix_hits']}, "
         f"{st['prefix_positions_saved']} positions reused")
-    if n_traced:
-        raise AssertionError(f"{n_traced} dispatches traced after warmup")
+    if traced:
+        raise AssertionError(f"dispatches traced after warmup: {traced}")
 
     params = eng.params
     eng.cache.pools = None              # free the server's KV pool first
@@ -300,9 +307,9 @@ def one_chip(seed):
         if toks_i != direct[i]:
             raise AssertionError(f"request {i}: HTTP tokens differ from "
                                  "the direct engine")
-    n_traced = jit_trace_count() - traces0
-    if n_traced:
-        raise AssertionError(f"direct run traced {n_traced} dispatches")
+    traced = retraced_since(traces0, jit_trace_counts())
+    if traced:
+        raise AssertionError(f"direct run traced dispatches: {traced}")
     log(f"HTTP tokens identical to a direct ContinuousEngine run "
         f"({len(direct)} requests); traces after warmup, through both: 0")
 

@@ -23,6 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import grouping
 
@@ -119,21 +120,29 @@ def quantize_blockwise(w, bits=4, block=64, solver="dp", lam=0.0, iters=30,
     64-elements-per-row blocks). Last axis must be divisible by ``block``.
     ``refine`` re-encodes each weight to its nearest codebook scale
     (improves the heuristic solvers; no-op for the exact DP).
+
+    Its three phases are host spans on a running ``jax.profiler`` trace
+    (``quantize.prepare``, ``quantize.solve``, ``quantize.encode``), so an
+    idle device gap between their programs names the host work in it.
     """
     w = jnp.asarray(w)
     if w.shape[-1] % block:
         raise ValueError(f"last dim {w.shape[-1]} not divisible by block {block}")
     g = 2 ** (bits - 1)
-    blocks = w.reshape(-1, block).astype(jnp.float32)
-    levels, scales = _solve(blocks, g, solver, lam, iters)
-    if refine:
-        levels = _nearest_refine(blocks, levels, scales)
-    signs = jnp.sign(blocks).astype(jnp.int8)
-    codes = (signs * (levels.astype(jnp.int8) + 1)).reshape(w.shape)
-    # scales keep the weight's batch dims so stacked (scan-over-layers)
-    # params stay scannable: (..., last//block, g)
-    scales = scales.reshape(*w.shape[:-1], w.shape[-1] // block, g)
-    return QTensor(codes, scales.astype(scale_dtype), bits, block, w.dtype)
+    with TraceAnnotation("quantize.prepare"):
+        blocks = w.reshape(-1, block).astype(jnp.float32)
+    with TraceAnnotation("quantize.solve"):
+        levels, scales = _solve(blocks, g, solver, lam, iters)
+        if refine:
+            levels = _nearest_refine(blocks, levels, scales)
+    with TraceAnnotation("quantize.encode"):
+        signs = jnp.sign(blocks).astype(jnp.int8)
+        codes = (signs * (levels.astype(jnp.int8) + 1)).reshape(w.shape)
+        # scales keep the weight's batch dims so stacked (scan-over-layers)
+        # params stay scannable: (..., last//block, g)
+        scales = scales.reshape(*w.shape[:-1], w.shape[-1] // block, g)
+        scales = scales.astype(scale_dtype)
+    return QTensor(codes, scales, bits, block, w.dtype)
 
 
 def quantize_pertensor(w, bits=6, solver="wdp", lam=0.0, iters=50,

@@ -1,4 +1,4 @@
-from .continuous import ContinuousEngine, jit_trace_count
+from .continuous import ContinuousEngine, jit_trace_count, jit_trace_counts
 from .engine import ServeEngine
 from .faults import NO_FAULTS, FaultEvent, FaultPlan, InjectedControlFault, \
     InjectedFault, InjectedOOM
@@ -27,4 +27,4 @@ __all__ = ["APIServer", "BrownoutLevel", "CompletionParams",
            "Scheduler", "Sequence", "ServeEngine", "ServeMetrics",
            "ValidationError", "Warming", "WatchdogTimeout",
            "compute_retry_after", "enumerate_traces", "jit_trace_count",
-           "parse_completion_request", "warm_engine"]
+           "jit_trace_counts", "parse_completion_request", "warm_engine"]

@@ -69,24 +69,31 @@ import numpy as np
 
 from .paged_cache import PagedKVCache
 from .scheduler import DECODE, FINISHED, Request, Scheduler, Sequence
+from .spans import PhaseClock
 
 # Trace-count probe (DESIGN.md Sec. 16): a Python-side counter bumped at
-# the top of every traced dispatch body. jit runs the Python body once per
-# trace, never per call, so after AOT warmup a steady-state serving run
-# must leave this number unchanged — the property the warmup tests and the
-# ``msb_traces_compiled_total`` metric assert. Process-global on purpose:
-# module-level jits share their compile cache across engines.
-_N_TRACES = 0
+# the top of every traced dispatch body, by the dispatch function's name.
+# jit runs the Python body once per trace, never per call, so after AOT
+# warmup a steady-state serving run must leave these numbers unchanged —
+# the property the warmup tests and the ``msb_traces_compiled_total``
+# metric assert. Process-global on purpose: module-level jits share their
+# compile cache across engines.
+_N_TRACES: Dict[str, int] = {}
 
 
-def _note_trace():
-    global _N_TRACES
-    _N_TRACES += 1
+def _note_trace(name: str):
+    _N_TRACES[name] = _N_TRACES.get(name, 0) + 1
 
 
 def jit_trace_count() -> int:
     """Traced-dispatch events so far in this process (monotonic)."""
-    return _N_TRACES
+    return sum(_N_TRACES.values())
+
+
+def jit_trace_counts() -> Dict[str, int]:
+    """``jit_trace_count()`` by dispatch function name, so a retrace
+    names the program that retraced."""
+    return dict(_N_TRACES)
 
 
 # Module-level jits, model static (frozen dataclass, hashable): every engine
@@ -99,7 +106,7 @@ def jit_trace_count() -> int:
 @functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2,))
 def _paged_step(model, kv_bits, pools, params, tokens, q_pos, kv_lens,
                 block_tables, slots):
-    _note_trace()
+    _note_trace("_paged_step")
     return model.paged_step(params, pools, tokens, q_pos, kv_lens,
                             block_tables, kv_bits=kv_bits, slots=slots)
 
@@ -108,7 +115,7 @@ def _paged_step(model, kv_bits, pools, params, tokens, q_pos, kv_lens,
 def _paged_prefill_packed(model, kv_bits, pools, params, tokens, seg_ids,
                           q_pos, kv_lens, block_tables, slots, last_idx,
                           seg_off):
-    _note_trace()
+    _note_trace("_paged_prefill_packed")
     return model.paged_prefill_packed(params, pools, tokens, seg_ids, q_pos,
                                       kv_lens, block_tables, slots, last_idx,
                                       seg_off, kv_bits=kv_bits)
@@ -119,7 +126,7 @@ def _paged_prefill_packed(model, kv_bits, pools, params, tokens, seg_ids,
 @functools.partial(jax.jit, static_argnums=(0, 1, 2), donate_argnums=(3,))
 def _paged_horizon_step(model, horizon, kv_bits, pools, params, tokens,
                         start_pos, n_left, eos_ids, block_tables, slots):
-    _note_trace()
+    _note_trace("_paged_horizon_step")
     return model.paged_decode_horizon(params, pools, tokens, start_pos,
                                       block_tables, n_left, eos_ids, horizon,
                                       kv_bits=kv_bits, slots=slots)
@@ -208,20 +215,20 @@ class ContinuousEngine:
                 _paged_prefill_packed, self.model, self.kv_bits)
         else:                              # parallel objects aren't hashable
             def _gspmd_step(pools, p, toks, qpos, kvl, bt, sl):
-                _note_trace()
+                _note_trace("_gspmd_step")
                 return self.model.paged_step(
                     p, pools, toks, qpos, kvl, bt, self.parallel,
                     kv_bits=self.kv_bits, slots=sl)
 
             def _gspmd_horizon(pools, p, toks, sp, nl, eos, bt, sl):
-                _note_trace()
+                _note_trace("_gspmd_horizon")
                 return self.model.paged_decode_horizon(
                     p, pools, toks, sp, bt, nl, eos, self.decode_horizon,
                     self.parallel, kv_bits=self.kv_bits, slots=sl)
 
             def _gspmd_prefill(pools, p, toks, segs, qpos, kvl, bt, sl, li,
                                so):
-                _note_trace()
+                _note_trace("_gspmd_prefill")
                 return self.model.paged_prefill_packed(
                     p, pools, toks, segs, qpos, kvl, bt, sl, li, so,
                     self.parallel, kv_bits=self.kv_bits)
@@ -254,6 +261,9 @@ class ContinuousEngine:
         # supervisor reads this to attribute a crash (DESIGN.md Sec. 14).
         self.last_step_rids: Tuple[int, ...] = ()
         self.last_step_kind: str = ""
+        # host time per phase of step() (serve/spans.py); stats() reports
+        # it as host_phase_seconds
+        self.phases = PhaseClock()
 
     def _init_tensor_parallel(self):
         """Shard params + page pools over ``mesh`` and build the shard_map
@@ -303,7 +313,7 @@ class ContinuousEngine:
         model, rep, kv_bits = self.model, P(), self.kv_bits
 
         def local_step(pools, params, tokens, q_pos, kv_lens, bt, slots):
-            _note_trace()
+            _note_trace("local_step")
             return model.paged_step(tp_localize(params), pools, tokens,
                                     q_pos, kv_lens, bt, parallel=tp,
                                     kv_bits=kv_bits, slots=slots)
@@ -322,7 +332,7 @@ class ContinuousEngine:
         # KV-head slice exactly like the unpacked step
         def local_prefill(pools, params, tokens, seg_ids, q_pos, kv_lens,
                           bt, slots, last_idx, seg_off):
-            _note_trace()
+            _note_trace("local_prefill")
             return model.paged_prefill_packed(
                 tp_localize(params), pools, tokens, seg_ids, q_pos, kv_lens,
                 bt, slots, last_idx, seg_off, parallel=tp, kv_bits=kv_bits)
@@ -340,7 +350,7 @@ class ContinuousEngine:
 
         def local_horizon(pools, params, tokens, start_pos, n_left, eos, bt,
                           slots):
-            _note_trace()
+            _note_trace("local_horizon")
             return model.paged_decode_horizon(
                 tp_localize(params), pools, tokens, start_pos, bt, n_left,
                 eos, horizon, parallel=tp, kv_bits=kv_bits, slots=slots)
@@ -414,7 +424,10 @@ class ContinuousEngine:
         self.last_step_kind = ""
         if self.faults.armed:
             self.faults.fire("step")
-        work = self.scheduler.schedule()
+        # engine.step opens only once the scheduler chose work, so a step
+        # blocked on admission or reservation leaves engine.schedule alone
+        with self.phases.span("engine.schedule"):
+            work = self.scheduler.schedule()
         if work is None:
             return False
         self.last_step_kind = work[0]
@@ -425,12 +438,14 @@ class ContinuousEngine:
         else:
             self.last_step_rids = tuple(s.req.req_id for s in work[1])
         self.n_steps += 1
-        if work[0] == "prefill":
-            self._run_prefill(*work[1:])
-        elif work[0] == "prefill_packed":
-            self._run_prefill_packed(*work[1:])
-        else:
-            self._run_decode(work[1])
+        with self.phases.span("engine.step", kind=work[0],
+                              rows=len(self.last_step_rids)):
+            if work[0] == "prefill":
+                self._run_prefill(*work[1:])
+            elif work[0] == "prefill_packed":
+                self._run_prefill_packed(*work[1:])
+            else:
+                self._run_decode(work[1])
         return True
 
     def fork_request(self, req_id, n=1, max_new_tokens=None,
@@ -585,6 +600,7 @@ class ContinuousEngine:
             "sheds_by_class": dict(s.n_sheds_by_class),
             "queue_depth": len(s.waiting),
             "running": len(s.running),
+            "host_phase_seconds": self.phases.snapshot(),
         }
 
     def drain_observations(self) -> Dict[str, List[int]]:
@@ -646,22 +662,24 @@ class ContinuousEngine:
         n = len(chunk_tokens)
         self.n_prefill_dispatches += 1
         self.n_prefill_segments += 1
-        tokens = np.zeros((1, c), np.int32)
-        tokens[0, :n] = chunk_tokens
-        q_pos = np.full((1, c), -1, np.int32)
-        q_pos[0, :n] = start + np.arange(n)
-        kv_lens = np.asarray([start + n], np.int32)
+        with self.phases.span("engine.prepare"):
+            tokens = np.zeros((1, c), np.int32)
+            tokens[0, :n] = chunk_tokens
+            q_pos = np.full((1, c), -1, np.int32)
+            q_pos[0, :n] = start + np.arange(n)
+            kv_lens = np.asarray([start + n], np.int32)
         logits = self._dispatch([seq.slot], tokens, q_pos, kv_lens)
         if self.faults.armed:
             self.faults.fire("apply")   # device written, host not yet
-        seq.cache_len = start + n
-        self.cache.commit(seq.slot, seq.cache_len)
-        self.cache.register_prefix(seq.slot, seq.tokens[:seq.cache_len])
-        if seq.cache_len == len(seq.tokens):        # prompt fully in cache
-            if not seq.is_done():                   # e.g. max_new_tokens=0
-                self._sample_and_advance(seq, logits[0])
-            seq.state = DECODE
-            self._maybe_finish(seq)
+        with self.phases.span("engine.apply"):
+            seq.cache_len = start + n
+            self.cache.commit(seq.slot, seq.cache_len)
+            self.cache.register_prefix(seq.slot, seq.tokens[:seq.cache_len])
+            if seq.cache_len == len(seq.tokens):    # prompt fully in cache
+                if not seq.is_done():               # e.g. max_new_tokens=0
+                    self._sample_and_advance(seq, logits[0])
+                seq.state = DECODE
+                self._maybe_finish(seq)
 
     def _run_prefill_packed(self, segs, bucket):
         """One ragged dispatch over up to ``max_batch`` segments' chunks
@@ -673,48 +691,54 @@ class ContinuousEngine:
         segment: commit, prefix registration, and sample-or-continue."""
         t = bucket
         s_max = self.max_batch
-        tokens = np.zeros((t,), np.int32)
-        seg_ids = np.full((t,), -1, np.int32)
-        q_pos = np.full((t,), -1, np.int32)
-        kv_lens = np.zeros((s_max,), np.int32)
-        slots = np.full((s_max,), -1, np.int32)
-        last_idx = np.zeros((s_max,), np.int32)
-        seg_off = np.zeros((s_max,), np.int32)
-        off = 0
-        for i, (seq, start, n) in enumerate(segs):
-            toks = seq.tokens
-            tokens[off:off + n] = toks[start:start + n]
-            seg_ids[off:off + n] = i
-            q_pos[off:off + n] = start + np.arange(n)
-            kv_lens[i] = start + n
-            slots[i] = seq.slot
-            seg_off[i] = off
-            last_idx[i] = off + n - 1
-            off += n
-        self.n_prefill_dispatches += 1
-        self.n_prefill_segments += len(segs)
-        if len(self.packed_segment_obs) < 4096:
-            self.packed_segment_obs.append(len(segs))
-        self.n_work_positions += t
-        bt = self.cache.table_rows([int(s) for s in slots])
-        logits, self.cache.pools = self._prefill_fn(
-            self.cache.pools, self.params, jnp.asarray(tokens),
-            jnp.asarray(seg_ids), jnp.asarray(q_pos), jnp.asarray(kv_lens),
-            bt, jnp.asarray(slots), jnp.asarray(last_idx),
-            jnp.asarray(seg_off))
+        with self.phases.span("engine.prepare"):
+            tokens = np.zeros((t,), np.int32)
+            seg_ids = np.full((t,), -1, np.int32)
+            q_pos = np.full((t,), -1, np.int32)
+            kv_lens = np.zeros((s_max,), np.int32)
+            slots = np.full((s_max,), -1, np.int32)
+            last_idx = np.zeros((s_max,), np.int32)
+            seg_off = np.zeros((s_max,), np.int32)
+            off = 0
+            for i, (seq, start, n) in enumerate(segs):
+                toks = seq.tokens
+                tokens[off:off + n] = toks[start:start + n]
+                seg_ids[off:off + n] = i
+                q_pos[off:off + n] = start + np.arange(n)
+                kv_lens[i] = start + n
+                slots[i] = seq.slot
+                seg_off[i] = off
+                last_idx[i] = off + n - 1
+                off += n
+            self.n_prefill_dispatches += 1
+            self.n_prefill_segments += len(segs)
+            if len(self.packed_segment_obs) < 4096:
+                self.packed_segment_obs.append(len(segs))
+            self.n_work_positions += t
+            bt = self.cache.table_rows([int(s) for s in slots])
+            args = [jnp.asarray(a) for a in (tokens, seg_ids, q_pos,
+                                             kv_lens)]
+            args += [bt] + [jnp.asarray(a) for a in (slots, last_idx,
+                                                     seg_off)]
+        with self.phases.span("engine.dispatch"):
+            logits, self.cache.pools = self._prefill_fn(
+                self.cache.pools, self.params, *args)
         self.n_host_syncs += 1          # blocking (S, vocab) logits fetch
-        logits = np.asarray(logits)
+        with self.phases.span("engine.wait"):
+            logits = np.asarray(logits)
         if self.faults.armed:
             self.faults.fire("apply")   # device written, host not yet
-        for i, (seq, start, n) in enumerate(segs):
-            seq.cache_len = start + n
-            self.cache.commit(seq.slot, seq.cache_len)
-            self.cache.register_prefix(seq.slot, seq.tokens[:seq.cache_len])
-            if seq.cache_len == len(seq.tokens):    # prompt fully in cache
-                if not seq.is_done():               # e.g. max_new_tokens=0
-                    self._sample_and_advance(seq, logits[i])
-                seq.state = DECODE
-                self._maybe_finish(seq)
+        with self.phases.span("engine.apply"):
+            for i, (seq, start, n) in enumerate(segs):
+                seq.cache_len = start + n
+                self.cache.commit(seq.slot, seq.cache_len)
+                self.cache.register_prefix(seq.slot,
+                                           seq.tokens[:seq.cache_len])
+                if seq.cache_len == len(seq.tokens):    # prompt in cache
+                    if not seq.is_done():           # e.g. max_new_tokens=0
+                        self._sample_and_advance(seq, logits[i])
+                    seq.state = DECODE
+                    self._maybe_finish(seq)
 
     def _decode_bucket(self, seqs):
         """Shared decode-batch shape policy: pad to the next power of two
@@ -735,26 +759,29 @@ class ContinuousEngine:
         self.n_decode_steps += 1
         if self.decode_horizon > 1:
             return self._run_decode_horizon(seqs)
-        b, slots, last = self._decode_bucket(seqs)
-        tokens = last[:, None]
-        q_pos = np.full((b, 1), -1, np.int32)
-        kv_lens = np.zeros((b,), np.int32)
-        for i, seq in enumerate(seqs):
-            q_pos[i, 0] = seq.n_total - 1
-            kv_lens[i] = seq.n_total
+        with self.phases.span("engine.prepare"):
+            b, slots, last = self._decode_bucket(seqs)
+            tokens = last[:, None]
+            q_pos = np.full((b, 1), -1, np.int32)
+            kv_lens = np.zeros((b,), np.int32)
+            for i, seq in enumerate(seqs):
+                q_pos[i, 0] = seq.n_total - 1
+                kv_lens[i] = seq.n_total
         logits = self._dispatch(slots, tokens, q_pos, kv_lens)
         if self.faults.armed:
             self.faults.fire("apply")   # device written, host not yet
-        for i, seq in enumerate(seqs):
-            seq.cache_len = seq.n_total
-            self.cache.commit(seq.slot, seq.cache_len)
-            # decode advances one position per step, so a page fills (and
-            # becomes registrable) exactly on the boundary commits
-            if self.prefix_cache and seq.cache_len % self.page_size == 0:
-                self.cache.register_prefix(seq.slot,
-                                           seq.tokens[:seq.cache_len])
-            self._sample_and_advance(seq, logits[i])
-            self._maybe_finish(seq)
+        with self.phases.span("engine.apply"):
+            for i, seq in enumerate(seqs):
+                seq.cache_len = seq.n_total
+                self.cache.commit(seq.slot, seq.cache_len)
+                # decode advances one position per step, so a page fills
+                # (and becomes registrable) exactly on the boundary commits
+                if self.prefix_cache and \
+                        seq.cache_len % self.page_size == 0:
+                    self.cache.register_prefix(seq.slot,
+                                               seq.tokens[:seq.cache_len])
+                self._sample_and_advance(seq, logits[i])
+                self._maybe_finish(seq)
 
     def _run_decode_horizon(self, seqs):
         """One fused dispatch = up to ``decode_horizon`` decode iterations
@@ -771,50 +798,58 @@ class ContinuousEngine:
         # retires fewer tokens per dispatch. Schedule-only, trace-free,
         # and token-identical (greedy output is horizon-independent).
         eff = self.scheduler.effective_horizon
-        b, slots, tokens = self._decode_bucket(seqs)
-        start_pos = np.full((b,), -1, np.int32)
-        n_left = np.zeros((b,), np.int32)
-        eos = np.full((b,), -1, np.int32)
-        for i, seq in enumerate(seqs):
-            start_pos[i] = seq.n_total - 1
-            n_left[i] = min(seq.req.max_new_tokens - len(seq.generated), eff)
-            if seq.req.eos_id is not None:
-                eos[i] = seq.req.eos_id
-        self.n_work_positions += b * h
-        bt = self.cache.table_rows(slots)
-        out_tok, valid, self.cache.pools = self._horizon_fn(
-            self.cache.pools, self.params, jnp.asarray(tokens),
-            jnp.asarray(start_pos), jnp.asarray(n_left), jnp.asarray(eos),
-            bt, jnp.asarray(np.asarray(slots, np.int32)))
-        out_tok, valid = np.asarray(out_tok), np.asarray(valid)
+        with self.phases.span("engine.prepare"):
+            b, slots, tokens = self._decode_bucket(seqs)
+            start_pos = np.full((b,), -1, np.int32)
+            n_left = np.zeros((b,), np.int32)
+            eos = np.full((b,), -1, np.int32)
+            for i, seq in enumerate(seqs):
+                start_pos[i] = seq.n_total - 1
+                n_left[i] = min(seq.req.max_new_tokens - len(seq.generated),
+                                eff)
+                if seq.req.eos_id is not None:
+                    eos[i] = seq.req.eos_id
+            self.n_work_positions += b * h
+            bt = self.cache.table_rows(slots)
+            args = [jnp.asarray(a) for a in (tokens, start_pos, n_left, eos)]
+            args += [bt, jnp.asarray(np.asarray(slots, np.int32))]
+        with self.phases.span("engine.dispatch"):
+            out_tok, valid, self.cache.pools = self._horizon_fn(
+                self.cache.pools, self.params, *args)
+        with self.phases.span("engine.wait"):
+            out_tok, valid = np.asarray(out_tok), np.asarray(valid)
         self.n_host_syncs += 1
         if self.faults.armed:
             self.faults.fire("apply")   # device written, host not yet
-        for i, seq in enumerate(seqs):
-            k = int(valid[i].sum())     # valid is a prefix mask per row
-            for t in out_tok[i, :k]:
-                seq.generated.append(int(t))
-            self.n_tokens_out += k
-            # the dispatch wrote K/V for each *input* token: positions
-            # n_total-1 .. n_total-2+k of the pre-dispatch sequence — the
-            # final sampled token is, as ever, not yet in the cache
-            seq.cache_len = seq.n_total - 1
-            self.cache.commit(seq.slot, seq.cache_len)
-            if self.prefix_cache:
-                self.cache.register_prefix(seq.slot,
-                                           seq.tokens[:seq.cache_len])
-            self._maybe_finish(seq)
+        with self.phases.span("engine.apply"):
+            for i, seq in enumerate(seqs):
+                k = int(valid[i].sum())     # valid is a prefix mask per row
+                for t in out_tok[i, :k]:
+                    seq.generated.append(int(t))
+                self.n_tokens_out += k
+                # the dispatch wrote K/V for each *input* token: positions
+                # n_total-1 .. n_total-2+k of the pre-dispatch sequence —
+                # the final sampled token is, as ever, not yet in the cache
+                seq.cache_len = seq.n_total - 1
+                self.cache.commit(seq.slot, seq.cache_len)
+                if self.prefix_cache:
+                    self.cache.register_prefix(seq.slot,
+                                               seq.tokens[:seq.cache_len])
+                self._maybe_finish(seq)
 
     # -- helpers --------------------------------------------------------------
     def _dispatch(self, slots, tokens, q_pos, kv_lens):
-        self.n_work_positions += tokens.size
-        bt = self.cache.table_rows(slots)
-        logits, self.cache.pools = self._step_fn(
-            self.cache.pools, self.params, jnp.asarray(tokens),
-            jnp.asarray(q_pos), jnp.asarray(kv_lens), bt,
-            jnp.asarray(np.asarray(slots, np.int32)))
+        with self.phases.span("engine.prepare"):
+            self.n_work_positions += tokens.size
+            bt = self.cache.table_rows(slots)
+            args = [jnp.asarray(a) for a in (tokens, q_pos, kv_lens)]
+            args += [bt, jnp.asarray(np.asarray(slots, np.int32))]
+        with self.phases.span("engine.dispatch"):
+            logits, self.cache.pools = self._step_fn(
+                self.cache.pools, self.params, *args)
         self.n_host_syncs += 1          # blocking (B, vocab) logits fetch
-        return np.asarray(logits)
+        with self.phases.span("engine.wait"):
+            return np.asarray(logits)
 
     def _sample_and_advance(self, seq, logits):
         seq.generated.append(int(np.argmax(logits)))

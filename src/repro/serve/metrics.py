@@ -38,6 +38,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 # runs sit in the 1-50ms/token range, real accelerators below that.
 LATENCY_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
                    0.5, 1.0, 2.5, 5.0, 10.0)
+# host hand-offs inside one process: 100us up
+HANDOFF_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                   0.025, 0.05, 0.1, 0.25, 0.5, 1.0)
 
 _LABEL_ESC = str.maketrans({"\\": r"\\", '"': r"\"", "\n": r"\n"})
 
@@ -317,6 +320,20 @@ class ServeMetrics:
             "msb_traces_compiled_total",
             "Process-wide jitted-dispatch traces entered (the steady-state "
             "serving delta after warmup should be zero)")
+        # host spans (serve/spans.py): where the engine thread's time goes
+        self.phase_seconds = r.counter(
+            "msb_engine_phase_seconds_total",
+            "Host seconds the engine spent in each phase of its loop, by "
+            "span name (engine.schedule; engine.step and its children "
+            "engine.prepare, engine.dispatch, engine.wait, engine.apply; "
+            "engine.commands, engine.stream, engine.housekeeping)",
+            labelnames=("phase",))
+        self.stream_delay = r.histogram(
+            "msb_stream_delay_seconds",
+            "Time from the engine loop's hand-off of a token event to the "
+            "end of the drain of the SSE write that carries it (one "
+            "observation per token-bearing frame)",
+            buckets=HANDOFF_BUCKETS)
         # supervision (DESIGN.md Sec. 14) — zero-valued until a supervised
         # engine syncs, so dashboards can alert on them unconditionally
         self.restarts = r.counter(
@@ -397,6 +414,7 @@ class ServeMetrics:
         # cache, so this ratchets even across sibling engines
         from .continuous import jit_trace_count
         self.traces_compiled.set_to(jit_trace_count())
+        self.sync_phases(st.get("host_phase_seconds", {}))
         drain = getattr(engine, "drain_observations", None)
         if drain is not None:
             obs = drain()
@@ -413,6 +431,13 @@ class ServeMetrics:
                 self.recovery.observe(t)
             self._recovery_seen = len(st["recovery_log"])
             self.set_health(st["health"])
+
+    def sync_phases(self, seconds: Dict[str, float]):
+        """Ratchet ``msb_engine_phase_seconds_total`` to cumulative host
+        seconds per span name (an engine's ``host_phase_seconds``, or the
+        ``EngineLoop``'s own phases)."""
+        for phase, v in seconds.items():
+            self.phase_seconds.set_to(v, phase=phase)
 
     def set_health(self, state: str):
         for s in ("ok", "warming", "degraded", "draining", "dead"):

@@ -57,6 +57,7 @@ from .lifecycle import (DONE, FINISH_CANCELLED, FINISH_ERROR, FINISH_LENGTH,
 from .metrics import Registry, ServeMetrics
 from .overload import OverloadController, compute_retry_after
 from .scheduler import Saturated
+from .spans import PhaseClock, span
 from .supervisor import DEAD, DEGRADED, DRAINING, OK, WARMING, Draining, \
     EngineDied, Recovering, Warming
 
@@ -120,6 +121,9 @@ class EngineLoop:
         self._cmds: "queue.Queue" = queue.Queue()
         self._detok_q: "queue.Queue" = queue.Queue()
         self._by_rid: Dict[int, RequestLifecycle] = {}
+        # host time per loop phase outside the engine's step (serve/
+        # spans.py), exported with the engine's own phases
+        self.phases = PhaseClock()
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="msb-engine-loop")
@@ -217,21 +221,29 @@ class EngineLoop:
                 finally:
                     self.warming = False
                 self.metrics.sync_engine(self.engine)
+            phases = self.phases
             while not self._stop.is_set():
                 busy = self.engine.has_work
-                self._drain_cmds(block=not busy)
+                if busy:
+                    with phases.span("engine.commands"):
+                        self._drain_cmds(block=False)
+                else:                   # idle: the blocking wait is no work
+                    self._drain_cmds(block=True)
                 if self._stop.is_set():
                     break
                 if self.engine.has_work:
                     self.engine.step()
-                    self._apply_updates(self.engine.stream_updates(),
-                                        time.monotonic())
-                self._drain_failures(time.monotonic())
-                self._check_deadlines(time.monotonic())
-                self._ensure_detok()
-                self.metrics.sync_engine(self.engine)
-                if self.overload is not None:
-                    self.overload.tick()
+                    with phases.span("engine.stream"):
+                        self._apply_updates(self.engine.stream_updates(),
+                                            time.monotonic())
+                with phases.span("engine.housekeeping"):
+                    self._drain_failures(time.monotonic())
+                    self._check_deadlines(time.monotonic())
+                    self._ensure_detok()
+                    self.metrics.sync_engine(self.engine)
+                    self.metrics.sync_phases(phases.snapshot())
+                    if self.overload is not None:
+                        self.overload.tick()
         except BaseException as e:
             # an unsupervised engine's step() crashing lands here (a
             # supervised one contains it); record the cause so probe/
@@ -362,7 +374,8 @@ class EngineLoop:
                         break
             if new:
                 lc.on_tokens(new, now)
-                batch.append((lc, ("tokens", list(new))))
+                # `now` rides to the SSE write: msb_stream_delay_seconds
+                batch.append((lc, ("tokens", list(new), now)))
             if reason is not None and not done:
                 self.engine.abort_request(rid)
                 done = True
@@ -407,17 +420,20 @@ class EngineLoop:
             batch = self._detok_q.get()
             if batch is None:
                 return
-            by_loop: Dict[object, list] = {}
-            for lc, event in batch:
-                if event[0] == "tokens":
-                    text = "".join(self.detokenize(t) for t in event[1])
-                    event = ("tokens", event[1], text)
-                by_loop.setdefault(lc.loop, []).append((lc.queue, event))
-            for loop, items in by_loop.items():
-                try:
-                    loop.call_soon_threadsafe(_distribute, items)
-                except RuntimeError:
-                    pass                        # handler's loop shut down
+            with span("frontdoor.detokenize"):
+                by_loop: Dict[object, list] = {}
+                for lc, event in batch:
+                    if event[0] == "tokens":
+                        _, ids, handed_off = event
+                        text = "".join(self.detokenize(t) for t in ids)
+                        event = ("tokens", ids, text, handed_off)
+                    by_loop.setdefault(lc.loop, []).append((lc.queue,
+                                                            event))
+                for loop, items in by_loop.items():
+                    try:
+                        loop.call_soon_threadsafe(_distribute, items)
+                    except RuntimeError:
+                        pass                    # handler's loop shut down
 
 
 class APIServer:
@@ -771,9 +787,10 @@ class APIServer:
                     events.append(lc.queue.get_nowait())
                 except asyncio.QueueEmpty:
                     break
-            out, done = bytearray(), False
+            out, done, handed_off = bytearray(), False, []
             for event in events:
                 if event[0] == "tokens":
+                    handed_off.append(event[3])
                     if self.faults.armed:
                         try:
                             # injected mid-stream connection drop: fires
@@ -801,11 +818,15 @@ class APIServer:
                     done = True
                     break
             try:
-                writer.write(bytes(out))
-                await writer.drain()
+                with span("frontdoor.write"):
+                    writer.write(bytes(out))
+                    await writer.drain()
             except (ConnectionError, BrokenPipeError):
                 self.engine_loop.cancel(lc, FINISH_CANCELLED)
                 return
+            now = time.monotonic()
+            for t in handed_off:
+                self.metrics.stream_delay.observe(now - t)
             if done:
                 return
 

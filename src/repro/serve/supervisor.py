@@ -207,6 +207,7 @@ class EngineSupervisor:
         self._base = {k: 0 for k in _ENGINE_COUNTERS}
         self._base_dicts: Dict[str, Dict[str, int]] = {
             k: {} for k in _ENGINE_DICT_COUNTERS}
+        self._base_phases: Dict[str, float] = {}    # host_phase_seconds
         self._aborts_extra = 0      # aborts of pending-replay requests
         self._overload = None       # OverloadController, if attached
         self.n_restarts = 0
@@ -613,6 +614,8 @@ class EngineSupervisor:
             base = self._base_dicts[k]
             for c, v in st.get(k, {}).items():
                 base[c] = base.get(c, 0) + v
+        for phase, v in st.get("host_phase_seconds", {}).items():
+            self._base_phases[phase] = self._base_phases.get(phase, 0.0) + v
 
     def stats(self) -> Dict[str, object]:
         """Aggregated monotonic counters across every incarnation, plus
@@ -626,6 +629,10 @@ class EngineSupervisor:
             for c, v in st.get(k, {}).items():
                 merged[c] = merged.get(c, 0) + v
             out[k] = merged
+        phases = dict(self._base_phases)
+        for phase, v in st.get("host_phase_seconds", {}).items():
+            phases[phase] = phases.get(phase, 0.0) + v
+        out["host_phase_seconds"] = phases
         out["aborts"] += self._aborts_extra
         out["queue_depth"] = st["queue_depth"] + len(self._pending_replay)
         out["running"] = st["running"]
